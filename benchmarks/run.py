@@ -65,6 +65,8 @@ def main() -> None:
     if args.list:
         list_modules()
         return
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     if args.shards is not None:
         os.environ["REPRO_SHARDS"] = str(args.shards)
     if args.shard_policy is not None:

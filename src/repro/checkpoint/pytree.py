@@ -52,6 +52,10 @@ def load_pytree(store: CheckpointStore, name: str, step: int, like):
         raw = store.get(f"{name}/{step}/{k}")
         arr = np.load(io.BytesIO(raw))
         want_dtype = getattr(leaf, "dtype", arr.dtype)
+        if arr.dtype.kind == "V":
+            # npy has no code for ml_dtypes (bfloat16, fp8): such leaves
+            # come back as raw void records of the same bytes
+            arr = arr.view(want_dtype)
         out.append(np.asarray(arr).astype(want_dtype))
     return jax.tree_util.tree_unflatten(treedef, out)
 

@@ -7,15 +7,18 @@ host path on the engine's integer columns (and ulp-identical on the
 float64 sketch state — see ``kernels/segment_reduce``), so routing is a
 pure performance decision: ``EngineConfig.use_kernels`` turns it on,
 ``kernel_min_batch`` keeps tiny probes on the host where dispatch
-overhead would dominate, and ``kernel_interpret`` picks the execution
-mode (``kernels.common.resolve_mode``).
+overhead would dominate, ``MAX_KERNEL_WORK`` keeps huge ones there (the
+kernels compare every query against the whole structure), and
+``kernel_interpret`` picks the execution mode
+(``kernels.common.resolve_mode``).
 
 Every routed call returns ``None`` when it declines (kernels off, batch
-too small, or keys outside the u32 dictionary-encoding range) — callers
-fall back to the host path, which produces the same bytes.  Wall-clock
-spent inside routed ops is emitted to the observer as a ``kernel_<op>_us``
-histogram per fused op class (real host microseconds, not simulated time —
-the one obs metric measured on the wall clock).
+too small or too large for the structure, or keys outside the u32
+dictionary-encoding range) — callers fall back to the host path, which
+produces the same bytes.  Wall-clock spent inside routed ops is emitted
+to the observer as a ``kernel_<op>_us`` histogram per fused op class
+(real host microseconds, not simulated time — the one obs metric
+measured on the wall clock).
 """
 
 from __future__ import annotations
@@ -27,6 +30,14 @@ import numpy as np
 
 # kernels pad sorted runs with 0xFFFFFFFE: keys must stay strictly below
 U32_KEY_LIMIT = np.uint64(0xFFFFFFFE)
+
+# The kernels are brute force: a call costs (batch x structure entries)
+# compare lanes, where the host's searchsorted costs O(batch log N).  Past
+# this product a call stays on the host — a 1024-key multi_get against a
+# full 64 MB kSST (run + k filter words) fits, a GC-Lookup batch of a
+# whole vSST does not.  The bound is an estimate from the v5e's vector
+# width, not yet a chip measurement.
+MAX_KERNEL_WORK = 1 << 33
 
 
 class KernelPolicy:
@@ -49,8 +60,11 @@ class KernelPolicy:
             self._mode = resolve_mode(self._interpret)
         return self._mode
 
-    def ready(self, n: int) -> bool:
-        return self.enabled and n >= self.min_batch
+    def ready(self, n: int, extent: int = 1) -> bool:
+        """Route a batch of ``n`` against a structure of ``extent``
+        entries?"""
+        return (self.enabled and n >= self.min_batch
+                and n * extent <= MAX_KERNEL_WORK)
 
 
 OFF_POLICY = KernelPolicy(False)
@@ -98,7 +112,7 @@ def op_timer(store, opclass: str):
 def memtable_probe(store, mt, keys):
     """Kernel-routed ``Memtable.get_batch``; None -> host path."""
     pol = policy_of(store.cfg)
-    if not pol.ready(len(keys)):
+    if not pol.ready(len(keys), len(mt)):
         return None
     mk, seqs, ety, vids, vsz, vf = mt.snapshot()
     n = len(mk)
@@ -119,14 +133,15 @@ def table_probe(store, t, keys, kraw):
     modulo to the table's filter size runs on the host (kernels stay in
     u32 lanes) and the resulting bit indices feed the fused probe."""
     pol = policy_of(store.cfg)
-    if not pol.ready(len(keys)):
+    bf = t.bloom
+    # the run plus k passes over the filter's u32 words
+    if not pol.ready(len(keys), t.n + len(kraw) * 2 * len(bf.bits)):
         return None
     if t.n == 0 or int(t.keys[-1]) >= int(U32_KEY_LIMIT) \
             or not _fits_u32(keys):
         return None
     from repro import kernels
     t0 = time.perf_counter()
-    bf = t.bloom
     bit_idx = (kraw % np.uint64(bf.nbits)).astype(np.uint32).T   # (Q, k)
     # pass the stable u64 backing words: ops caches the padded device copy
     # against this array's identity (a .view here would defeat the cache)
@@ -139,9 +154,9 @@ def table_probe(store, t, keys, kraw):
 def assign_files(store, lvl: int, keys):
     """Kernel-routed ``Version.assign_files``; None -> host path."""
     pol = policy_of(store.cfg)
-    if not pol.ready(len(keys)):
-        return None
     mins, maxs = store.version.level_bounds(lvl)
+    if not pol.ready(len(keys), len(mins)):
+        return None
     if (len(mins) == 0 or int(maxs[-1]) >= int(U32_KEY_LIMIT)
             or not _fits_u32(keys)):
         return None
@@ -156,7 +171,7 @@ def assign_files(store, lvl: int, keys):
 def table_find(store, t, keys):
     """Kernel-routed ``SSTable.find``; None -> host path."""
     pol = policy_of(store.cfg)
-    if not pol.ready(len(keys)):
+    if not pol.ready(len(keys), t.n):
         return None
     if t.n == 0 or int(t.keys[-1]) >= int(U32_KEY_LIMIT) \
             or not _fits_u32(keys):
@@ -177,6 +192,6 @@ def plan_runs(store, ranks, pos):
         return None
     from repro import kernels
     t0 = time.perf_counter()
-    out = kernels.run_coalesce(ranks, pos, window=pol.window, mode=pol.mode)
+    out = kernels.run_coalesce(ranks, pos, window=pol.window)
     _emit(store, "run_coalesce", t0)
     return out

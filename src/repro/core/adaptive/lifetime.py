@@ -61,7 +61,8 @@ class LifetimeEstimator:
                 # lazy per-group decay: scale by time since last observation
                 self.hist[sel] *= (0.5 ** (iv / self.half_life))[:, None]
             pol = self.policy
-            if pol is not None and pol.ready(len(sel)):
+            if pol is not None and pol.ready(len(sel),
+                                             len(sel) * N_BUCKETS):
                 # one-hot bucket rows via segment_sum; adding the zero
                 # columns is exact (x + 0.0 == x for the non-negative hist)
                 from repro import kernels

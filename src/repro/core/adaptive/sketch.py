@@ -91,7 +91,9 @@ class DecaySketch:
                 np.add.at(self.counts[r], idx[r], w)
             return
         pol = self.policy
-        if pol is not None and pol.ready(len(keys)):
+        # segment_sum matches depth*n ids against depth*width slots
+        if pol is not None and pol.ready(len(keys),
+                                         self.depth ** 2 * self.width):
             from repro import kernels
             flat = (idx + np.arange(self.depth)[:, None] * self.width).ravel()
             seg = kernels.segment_sum(flat, self.depth * self.width,
@@ -108,7 +110,7 @@ class DecaySketch:
             return np.zeros(0, np.float64)
         idx = self._rows(keys)
         pol = self.policy
-        if pol is not None and pol.ready(len(keys)):
+        if pol is not None and pol.ready(len(keys), self.depth * self.width):
             from repro import kernels
             # (depth, width) f64 -> little-endian (lo, hi) u32 planes;
             # lexicographic pair-min == numeric min for non-negative doubles

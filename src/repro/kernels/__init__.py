@@ -2,6 +2,7 @@
 
 Validated in interpret mode on CPU against the pure-jnp oracles in each
 package's ref.py; lowered with explicit BlockSpec VMEM tiling for TPU.
+``run_coalesce`` is a jitted jnp graph with no Pallas kernel.
 The engine routes its batched hot paths here through ``core/accel.py``
 (``EngineConfig.use_kernels``, DESIGN.md §12).
 """
@@ -13,7 +14,7 @@ from .lookup_probe import (interval_rank, lookup_probe, lookup_probe_ref,
 from .merge import merge_dedup, merge_dedup_ref
 from .partition import hot_cold_partition, hot_cold_partition_ref
 from .paged_gather import page_gather, page_gather_ref
-from .run_coalesce import run_coalesce, run_coalesce_ref
+from .run_coalesce import run_coalesce
 from .segment_reduce import (gather_min64, gather_min64_ref, segment_sum,
                              segment_sum_ref)
 
@@ -23,6 +24,6 @@ __all__ = [
     "hot_cold_partition", "hot_cold_partition_ref",
     "page_gather", "page_gather_ref",
     "lookup_probe", "lookup_probe_ref", "rank_probe", "rank_probe_ref",
-    "interval_rank", "run_coalesce", "run_coalesce_ref",
+    "interval_rank", "run_coalesce",
     "segment_sum", "segment_sum_ref", "gather_min64", "gather_min64_ref",
 ]

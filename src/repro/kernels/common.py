@@ -6,9 +6,10 @@ keys would be dictionary-encoded to u32 at the table level).  TPU vector
 units have no efficient per-lane gather from VMEM, so every kernel is built
 from gather-free primitives:
 
-  * membership/rank  -> tiled compare-and-reduce (brute-force compares beat
-    pointer chasing on the VPU),
-  * bloom word fetch -> one-hot multiply-reduce ("gather via matmul"),
+  * membership/rank  -> tiled brute-force compare-and-reduce (no pointer
+    chasing; at a 1.2 M-entry run on a v5e it measured slower per call
+    than XLA's searchsorted graph — PERF.md),
+  * bloom word fetch -> one-hot select-reduce,
   * merge/sort       -> bitonic compare-exchange networks at fixed strides,
   * page fetch       -> block-level dynamic slices driven by scalar-prefetch
     (the one dynamic-indexing form TPUs do support).
@@ -22,6 +23,8 @@ import weakref
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 MIX1 = np.uint32(0x85EBCA6B)
 MIX2 = np.uint32(0xC2B2AE35)
@@ -29,9 +32,14 @@ MIX2 = np.uint32(0xC2B2AE35)
 # ---- canonical VMEM tile sizes (the only place magic tiles may live;
 # enforced by the config-discipline scavlint pass) ----
 QUERY_TILE = 256        # query rows per grid step (sublane-friendly)
-TABLE_CHUNK = 512       # sorted-run chunk streamed per compare-reduce step
-WORD_CHUNK = 512        # u32 filter words per one-hot fetch step
-SLOT_TILE = 512         # output slots per segment-reduce grid step
+SLOT_TILE = 256         # output slots per segment-reduce grid step
+LANES = 128             # streamed columns are lane-dense (rows, LANES)
+LANE_BITS = 7           # log2(LANES)
+SUBLANES = 8            # rows per (8, 128) u32 vreg tile
+MIN_COLUMN = SUBLANES * LANES   # smallest padded streamed column
+BLOCK_ROWS = 256        # max rows per streamed VMEM block (128 KiB u32)
+TABLE_CHUNK = 512       # 1-D chunks of the unrouted bloom/gc_lookup kernels
+WORD_CHUNK = 512
 
 # u32 lane sentinels: queries pad with MAX, table runs with MAX-1, so real
 # keys must stay strictly below MAX-1 (checked by the ops wrappers)
@@ -84,6 +92,89 @@ def resolve_mode(kernel_interpret: bool | None) -> str:
     if kernel_interpret is None:
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return "interpret" if kernel_interpret else "pallas"
+
+
+# ---- streamed columns (the routed kernels, DESIGN.md §12) ----
+# A per-structure column (sorted run, filter words, sketch row) is padded
+# to a power of two >= MIN_COLUMN and viewed lane-dense as (rows, LANES).
+# It streams through VMEM one (block_rows, LANES) block per step of the
+# grid's second, "arbitrary" axis, so VMEM use is flat in structure size.
+STREAM_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
+
+
+def column_len(n: int) -> int:
+    """Padded length of a streamed column of ``n`` entries."""
+    return max(MIN_COLUMN, next_pow2(n))
+
+
+def column_blocks(rows: int) -> tuple[int, int]:
+    """(block_rows, n_blocks) of a (rows, LANES) column."""
+    br = min(BLOCK_ROWS, rows)
+    return br, rows // br
+
+
+def column_spec(rows: int, steps: int, lead: tuple = ()) -> pl.BlockSpec:
+    """BlockSpec streaming a ``lead + (rows, LANES)`` column along grid
+    axis 1.  Steps past the column's last block revisit it (no new DMA);
+    kernels skip their work there with ``pl.when``."""
+    br, nb = column_blocks(rows)
+    zeros = (0,) * len(lead)
+    return pl.BlockSpec(lead + (br, LANES),
+                        lambda i, j: zeros + (jnp.minimum(j, nb - 1), 0))
+
+
+def tile_spec(width: int, tile: int = QUERY_TILE) -> pl.BlockSpec:
+    """BlockSpec of a (tile, width) row tile, resident across grid axis 1
+    (outputs accumulate in it: a revisited block)."""
+    return pl.BlockSpec((tile, width), lambda i, j: (i, 0))
+
+
+def zero_first(j, *outs):
+    """Zero revisited output blocks at the first step of grid axis 1."""
+    @pl.when(j == 0)
+    def _():
+        for o in outs:
+            o[...] = jnp.zeros(o.shape, o.dtype)
+
+
+def as_i32(a):
+    """int32 bit pattern of a u32 array (Mosaic reduces no unsigned)."""
+    return jax.lax.bitcast_convert_type(a, jnp.int32)
+
+
+def as_u32(a):
+    return jax.lax.bitcast_convert_type(a, jnp.uint32)
+
+
+def fold_rows(blk_ref, init, step):
+    """Fold ``step(acc, row, r)`` over the rows of a (rows, LANES) block,
+    one (SUBLANES, LANES) tile load per loop trip; ``row`` is (1, LANES)
+    and ``r`` its row index in the block."""
+    def body(t, acc):
+        r0 = pl.multiple_of(t * SUBLANES, SUBLANES)
+        tile = blk_ref[pl.ds(r0, SUBLANES), :]
+        for s in range(SUBLANES):
+            acc = step(acc, tile[s:s + 1, :], r0 + s)
+        return acc
+    return jax.lax.fori_loop(0, blk_ref.shape[0] // SUBLANES, body, init)
+
+
+def fetch_block(blk_ref, idx, j):
+    """Per-query value at flat column index ``idx`` (QT, 1) when it falls
+    in block ``j`` of a streamed column, else 0: a one-hot select over the
+    block's rows, then a lane reduction that sums one nonzero term, so the
+    bits come back unchanged."""
+    row = (idx >> LANE_BITS) - j * blk_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def step(acc, vals, r):
+        return jnp.where(row == r, vals, acc)
+
+    acc = fold_rows(blk_ref, jnp.zeros((idx.shape[0], LANES), jnp.int32),
+                    step)
+    return jnp.where(lane == (idx & (LANES - 1)), acc, 0).sum(
+        axis=1, keepdims=True)
 
 
 def mix32(x: jnp.ndarray) -> jnp.ndarray:
@@ -143,67 +234,6 @@ def _cmpx(keys, payloads, stride, dir_up_row):
                                 jnp.where(swap, plo, phi)],
                                axis=1).reshape(n))
     return keys, tuple(out_p)
-
-
-def _cmpx2(k1, k2, payloads, stride, dir_up_row):
-    """Lexicographic compare-exchange on key *pairs* (k1 major, k2 minor)
-    at fixed ``stride`` — same gather-free reshape-and-swap as ``_cmpx``."""
-    n = k1.shape[0]
-    a1, a2 = k1.reshape(-1, 2, stride), k2.reshape(-1, 2, stride)
-    lo1, hi1 = a1[:, 0, :], a1[:, 1, :]
-    lo2, hi2 = a2[:, 0, :], a2[:, 1, :]
-    up = dir_up_row[:, None]
-    gt = (lo1 > hi1) | ((lo1 == hi1) & (lo2 > hi2))
-    lt = (lo1 < hi1) | ((lo1 == hi1) & (lo2 < hi2))
-    swap = jnp.where(up, gt, lt)
-
-    def _sw(lo, hi):
-        return jnp.stack([jnp.where(swap, hi, lo), jnp.where(swap, lo, hi)],
-                         axis=1).reshape(n)
-
-    return _sw(lo1, hi1), _sw(lo2, hi2), tuple(
-        _sw(p.reshape(-1, 2, stride)[:, 0, :],
-            p.reshape(-1, 2, stride)[:, 1, :]) for p in payloads)
-
-
-def bitonic_sort_pairs(k1, k2, *payloads, ascending=True):
-    """Bitonic sort by the lexicographic pair key (k1, k2); payloads ride
-    along.  Gather-free fixed-stride network, power-of-two length."""
-    n = k1.shape[0]
-    assert (n & (n - 1)) == 0
-    size = 2
-    while size <= n:
-        stride = size // 2
-        while stride >= 1:
-            rows = n // (2 * stride)
-            row_base = jnp.arange(rows) * (2 * stride)
-            dir_up = ((row_base & size) == 0) == ascending
-            k1, k2, payloads = _cmpx2(k1, k2, payloads, stride, dir_up)
-            stride //= 2
-        size *= 2
-    return (k1, k2) + payloads
-
-
-def prefix_sum(x):
-    """Inclusive prefix sum via Hillis-Steele shifted adds (gather-free:
-    log2(n) fixed-offset slice+concat passes)."""
-    n = x.shape[0]
-    s = 1
-    while s < n:
-        x = x + jnp.concatenate([jnp.zeros((s,), x.dtype), x[:-s]])
-        s *= 2
-    return x
-
-
-def prefix_max(x):
-    """Inclusive running maximum, same shifted-scan shape as prefix_sum."""
-    n = x.shape[0]
-    s = 1
-    while s < n:
-        lead = jnp.full((s,), x[0], x.dtype) if n else x
-        x = jnp.maximum(x, jnp.concatenate([lead, x[:-s]]))
-        s *= 2
-    return x
 
 
 def bitonic_sort(keys, *payloads, ascending=True):

@@ -1,11 +1,12 @@
 """Dispatching wrappers for the fused lookup-probe ops.
 
 Padding contract: queries pad to a pow2 multiple of QUERY_TILE (bounds jit
-retracing across ragged batch remainders), sorted runs pad with the
-``U32_TABLE_PAD`` sentinel to a pow2 multiple of TABLE_CHUNK, filter words
-zero-pad to a pow2 multiple of WORD_CHUNK.  Real keys must stay strictly
-below the sentinel (u64 keys are accepted when they fit — the engine's
-dictionary-encoding contract).
+retracing across ragged batch remainders); sorted runs pad with the
+``U32_TABLE_PAD`` sentinel and filter words with zeros to a power of two
+>= MIN_COLUMN (``common.column_len``), kept as flat columns for the XLA
+mode and viewed lane-dense (rows, 128) for the kernels.  Real keys must
+stay strictly below the sentinel (u64 keys are accepted when they fit —
+the engine's dictionary-encoding contract).
 
 Dispatch-overhead discipline (the CPU roofline in benchmarks/
 kernels_bench.py): per-structure operands — the sorted run, the filter
@@ -27,9 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import (QUERY_TILE, TABLE_CHUNK, U32_MAX, U32_TABLE_PAD,
-                      WORD_CHUNK, device_cached, next_pow2, resolve_mode,
-                      round_up)
+from ..common import (QUERY_TILE, U32_MAX, U32_TABLE_PAD, column_len,
+                      device_cached, next_pow2, resolve_mode, round_up)
 from .kernel import count_le_pallas, lookup_probe_pallas, rank_probe_pallas
 from .ref import count_le_ref, lookup_probe_ref, rank_probe_ref
 
@@ -59,7 +59,7 @@ def _run_dev(run: np.ndarray, fill, tag: str):
     """Cached padded device copy of an immutable sorted key column."""
     def build():
         n = run.shape[0]
-        p = np.full(max(TABLE_CHUNK, next_pow2(n)), fill, np.uint32)
+        p = np.full(column_len(n), fill, np.uint32)
         p[:n] = run
         return jnp.asarray(p)
     return device_cached(run, tag, build)
@@ -71,7 +71,7 @@ def _words_dev(words: np.ndarray):
     def build():
         w = words.view(np.uint32) if words.dtype == np.uint64 \
             else np.asarray(words, np.uint32)
-        p = np.zeros(max(WORD_CHUNK, next_pow2(w.shape[0])), np.uint32)
+        p = np.zeros(column_len(w.shape[0]), np.uint32)
         p[:w.shape[0]] = w
         return jnp.asarray(p)
     return device_cached(words, "words", build)

@@ -1,9 +1,9 @@
 """Dispatching wrappers for the segment-reduce ops.
 
-``segment_sum`` pads ids to a pow2 multiple of TABLE_CHUNK (masked with
--1) and the slot extent to a pow2 multiple of SLOT_TILE, so both the jit
-cache and the Pallas grid see a bounded family of shapes; callers slice
-the trimmed counts.  ``gather_min64`` carries float64 sketch state as
+``segment_sum`` pads ids to ``common.column_len`` (masked with -1) and
+the slot extent to a pow2 multiple of SLOT_TILE, so both the jit cache
+and the Pallas grid see a bounded family of shapes; callers slice the
+trimmed counts.  ``gather_min64`` carries float64 sketch state as
 (hi, lo) u32 bit-pattern planes — exact for the sketch's non-negative
 counters, no x64 mode needed inside the kernels.
 """
@@ -13,8 +13,8 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from ..common import (QUERY_TILE, SLOT_TILE, TABLE_CHUNK, U32_MAX,
-                      next_pow2, resolve_mode, round_up)
+from ..common import (QUERY_TILE, SLOT_TILE, U32_MAX, column_len, next_pow2,
+                      resolve_mode, round_up)
 from .kernel import gather_min64_pallas, segment_sum_pallas
 from .ref import gather_min64_ref, segment_sum_ref
 
@@ -32,7 +32,7 @@ def segment_sum(ids, n_slots: int, *, mode=None):
     if ids.shape[0] == 0 or n_slots == 0:
         return np.zeros(n_slots, np.int64)
     sp = round_up(max(SLOT_TILE, next_pow2(n_slots)), SLOT_TILE)
-    ip = np.full(max(TABLE_CHUNK, next_pow2(ids.shape[0])), -1, np.int32)
+    ip = np.full(column_len(ids.shape[0]), -1, np.int32)
     ip[:ids.shape[0]] = ids
     if mode == "xla":
         counts = _xla_seg(ip, n_slots=sp)
@@ -56,7 +56,7 @@ def gather_min64(hi, lo, idx, *, mode=None):
     if q == 0:
         return np.zeros(0, np.uint32), np.zeros(0, np.uint32)
     d, w = hi.shape
-    wp = round_up(max(TABLE_CHUNK, next_pow2(w)), TABLE_CHUNK)
+    wp = column_len(w)
     qp = round_up(max(QUERY_TILE, next_pow2(q)), QUERY_TILE)
     # pad slots with all-ones (the largest pair) — real idx never lands
     # there, and padded query rows are trimmed anyway

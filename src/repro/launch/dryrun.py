@@ -113,17 +113,8 @@ def _apply_opt(cfg):
     return dataclasses.replace(cfg, attn_impl="chunked", gqa_grouped=True)
 
 
-def _cost_dict(compiled) -> dict:
-    """cost_analysis() returns a dict on new jax, a per-computation list of
-    dicts on older releases — normalize to one dict."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _cost_fields(compiled) -> dict:
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     return {"flops": cost.get("flops", 0.0),
             "bytes": cost.get("bytes accessed", 0.0),
@@ -249,7 +240,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     compiled = lowered.compile()
     compile_s = time.time() - t0
 
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

@@ -14,7 +14,7 @@ Activations are batch-sharded over the FSDP axes.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.layers import ShardCtx
 
@@ -22,7 +22,9 @@ from repro.models.layers import ShardCtx
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model's with_sharding_constraint hints need them
+    # (make_mesh defaults to Explicit axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh: Mesh):

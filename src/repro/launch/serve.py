@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import use_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -32,6 +33,7 @@ def main():
                     default="hash")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

@@ -29,6 +29,7 @@ from repro.checkpoint.pytree import (drop_steps, load_pytree, save_pytree,
 from repro.checkpoint.store import CheckpointStore
 from repro.configs import get_config
 from repro.data.pipeline import PipelineConfig, TokenPipeline
+from repro.launch.cache import use_compile_cache
 from repro.models.model import build_model
 from repro.train.trainer import (TrainConfig, init_opt_state,
                                  make_train_step)
@@ -151,6 +152,7 @@ def main():
     ap.add_argument("--fail-at-step", type=int, default=None)
     ap.add_argument("--fresh", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     run(args)
 
 

@@ -111,6 +111,20 @@ def test_pytree_roundtrip_and_retention(tmp_path):
     st.close()
 
 
+def test_pytree_roundtrip_bfloat16(tmp_path):
+    """Full-width model configs keep bfloat16 params, which npy stores as
+    raw void records: they must come back bit-identical."""
+    st = CheckpointStore(tmp_path)
+    w = jax.random.normal(jax.random.key(0), (3, 5)).astype(jnp.bfloat16)
+    save_pytree(st, "m", 1, {"w": w})
+    got = load_pytree(st, "m", 1, {"w": jax.ShapeDtypeStruct(w.shape,
+                                                              w.dtype)})
+    assert got["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(got["w"].view(np.uint16),
+                                  np.asarray(w).view(np.uint16))
+    st.close()
+
+
 def test_naive_engine_keeps_space_longer(tmp_path):
     def churn(engine):
         root = tmp_path / engine

@@ -78,10 +78,12 @@ def test_crash_restart_resumes_bitexact(tmp_path):
 def test_mesh_and_param_shardings_resolve():
     """In-process sanity of the sharding resolution (1-device mesh)."""
     import jax
+    from jax.sharding import AxisType
     from repro.configs import get_config
     from repro.launch import mesh as meshlib
     from repro.models.model import build_model
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     for arch in ("smollm_360m", "jamba_15_large", "whisper_base"):
         model = build_model(get_config(arch, smoke=True))
         sh = meshlib.param_shardings(model, mesh)

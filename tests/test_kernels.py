@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracle,
-swept over shapes/dtypes + hypothesis property tests."""
+swept over shapes/dtypes + hypothesis property tests.  The routed kernels
+(lookup_probe, segment_reduce) are also checked at shapes whose streamed
+columns span several VMEM blocks (``common.BLOCK_ROWS``)."""
 
 import jax
 import jax.numpy as jnp
@@ -17,8 +19,8 @@ from repro.kernels import (bloom_build, bloom_probe, bloom_probe_ref,
                            rank_probe, run_coalesce, segment_sum)
 from repro.kernels.common import bitonic_merge, bitonic_sort
 
-# kernels.lookup_probe / kernels.run_coalesce / kernels.segment_reduce ops
-# run in both modes: the jitted XLA oracle and the Pallas interpreter.
+# kernels.lookup_probe / kernels.segment_reduce ops run in both modes: the
+# jitted XLA oracle and the Pallas interpreter.
 MODES = ("xla", "interpret")
 
 # largest u32 value the dispatchers accept (pad sentinel is 0xFFFFFFFE)
@@ -266,6 +268,53 @@ def test_interval_rank_matches_assign_files(mode):
     assert_array_equal(got, np.where(ok, pos, -1))
 
 
+# runs, filters and level bounds whose lane-dense columns span several
+# VMEM blocks (BLOCK_ROWS * 128 = 32768 entries per block), and filters
+# whose block count differs from the run's
+@pytest.mark.parametrize("q,n,nwords", [(256, 40_000, 512),
+                                        (700, 70_000, 40_000),
+                                        (300, 1024, 70_000)])
+def test_lookup_probe_streamed_blocks(q, n, nwords):
+    rng = np.random.default_rng(n + nwords)
+    space = np.arange(1, 4 * n + 2, dtype=np.uint32)
+    table = np.sort(rng.choice(space, n, replace=False))
+    table[-1] = BOUNDARY
+    queries = np.concatenate([
+        rng.choice(table, q // 2),
+        rng.integers(0, 4 * n + 9, q - q // 2).astype(np.uint32)])
+    queries[0] = BOUNDARY
+    words = rng.integers(0, 1 << 32, nwords, dtype=np.uint64)
+    words = words.astype(np.uint32)
+    bit_idx = rng.integers(0, 32 * nwords, (q, 7)).astype(np.uint32)
+    bit_idx[:, 0] = 32 * nwords - 1             # last bit of the last word
+    may, found, rank = lookup_probe(queries, table, bit_idx, words,
+                                    mode="interpret")
+    assert_array_equal(may, _bloom_oracle(bit_idx, words))
+    wf, wr = _rank_oracle(queries, table)
+    assert_array_equal(found, wf)
+    assert_array_equal(rank, wr)
+    f2, r2 = rank_probe(queries, table, mode="interpret")
+    assert_array_equal(f2, wf)
+    assert_array_equal(r2, wr)
+
+
+@pytest.mark.parametrize("n_files", [600, 1024, 40_000])
+def test_interval_rank_streamed_blocks(n_files):
+    """count_le over level bounds of >= 1024 files (several vreg rows, and
+    several VMEM blocks at 40k)."""
+    rng = np.random.default_rng(n_files)
+    e = np.sort(rng.choice(np.arange(0, 8 * n_files, dtype=np.uint64),
+                           2 * n_files, replace=False))
+    mins, maxs = e[0::2], e[1::2]
+    q = np.concatenate([mins[::7], maxs[::5], rng.integers(
+        0, 8 * n_files, 300).astype(np.uint64)])
+    got = interval_rank(q, mins, maxs, mode="interpret")
+    pos = np.searchsorted(mins, q, side="right") - 1
+    safe = np.where(pos >= 0, pos, 0)
+    ok = (pos >= 0) & (q <= maxs[safe])
+    assert_array_equal(got, np.where(ok, pos, -1))
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.lists(st.integers(0, 10_000), min_size=2, max_size=40,
                 unique=True),
@@ -302,23 +351,22 @@ def _runs_from_kernel(rank_s, pos_s, keep, start):
     return out
 
 
-@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("m", [100, 4097])
 @pytest.mark.parametrize("window", [None, 1, 3, 16])
 @pytest.mark.parametrize("case", ["empty", "single", "dups", "mixed"])
-def test_run_coalesce_matches_host_planner(case, window, mode):
-    rng = np.random.default_rng(hash((case, window)) % (1 << 32))
+def test_run_coalesce_matches_host_planner(case, window, m):
+    rng = np.random.default_rng(hash((case, window, m)) % (1 << 32))
     if case == "empty":
         rank = pos = np.zeros(0, np.int64)
     elif case == "single":
         rank, pos = np.array([3]), np.array([77])
     elif case == "dups":
-        rank = np.zeros(12, np.int64)
-        pos = np.full(12, 5, np.int64)          # all-duplicate positions
+        rank = np.zeros(m, np.int64)
+        pos = np.full(m, 5, np.int64)           # all-duplicate positions
     else:
-        m = 100 if mode == "interpret" else 700   # non-tile-multiple
-        rank = rng.integers(0, 5, m)
-        pos = rng.integers(0, 40, m)
-    got = run_coalesce(rank, pos, window=window, mode=mode)
+        rank = rng.integers(0, 5, m)            # non-power-of-two length
+        pos = rng.integers(0, m // 2, m)
+    got = run_coalesce(rank, pos, window=window)
     assert _runs_from_kernel(*got) == _coalesce_oracle(rank, pos, window)
 
 
@@ -329,7 +377,7 @@ def test_run_coalesce_matches_host_planner(case, window, mode):
 def test_run_coalesce_property(pairs, window):
     rank = np.array([p[0] for p in pairs], np.int64)
     pos = np.array([p[1] for p in pairs], np.int64)
-    got = run_coalesce(rank, pos, window=window, mode="xla")
+    got = run_coalesce(rank, pos, window=window)
     assert _runs_from_kernel(*got) == _coalesce_oracle(rank, pos, window)
 
 
@@ -343,6 +391,17 @@ def test_segment_sum_matches_bincount(m, slots, mode):
     rng = np.random.default_rng(m * 31 + slots)
     ids = rng.integers(-1, slots + 2, m)        # includes out-of-range
     got = segment_sum(ids, slots, mode=mode)
+    valid = ids[(ids >= 0) & (ids < slots)]
+    assert_array_equal(got, np.bincount(valid, minlength=slots))
+
+
+@pytest.mark.parametrize("m,slots", [(50_000, 8192), (2048, 700)])
+def test_segment_sum_streamed_blocks(m, slots):
+    """Id columns spanning several VMEM blocks, slot extents spanning
+    several slot tiles (the sketch's (2, 4096) update is 8192 slots)."""
+    rng = np.random.default_rng(m + slots)
+    ids = rng.integers(-1, slots + 2, m)
+    got = segment_sum(ids, slots, mode="interpret")
     valid = ids[(ids >= 0) & (ids < slots)]
     assert_array_equal(got, np.bincount(valid, minlength=slots))
 
@@ -365,7 +424,8 @@ def _min64_oracle(vals, idx):
 
 
 @pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("d,w,q", [(1, 1, 1), (2, 50, 33), (4, 100, 64)])
+@pytest.mark.parametrize("d,w,q", [(1, 1, 1), (2, 50, 33), (4, 100, 64),
+                                   (2, 4096, 1024), (3, 70_000, 300)])
 def test_gather_min64_reconstructs_f64_min(d, w, q, mode):
     rng = np.random.default_rng(d * 100 + w + q)
     vals = (rng.random((d, w)) * 1e6)           # non-negative f64
