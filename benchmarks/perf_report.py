@@ -42,7 +42,8 @@ GATE_WINDOW = 5         # trailing entries (per section+scale) to median
 # inspectable via `python -m repro.obs summarize` on a --trace dump)
 OBS_HISTS = ("write_us", "multi_get_us", "stall_us", "flush_us",
              "compact_us", "gc_us", "gc_rewrite_bytes",
-             "gc_reclaimed_bytes", "kernel_lookup_probe_us",
+             "gc_reclaimed_bytes", "kernel_rank_probe_us",
+             "kernel_interval_rank_us", "kernel_lookup_probe_us",
              "kernel_run_coalesce_us", "kernel_segment_reduce_us")
 OBS_ENGINES = ("rocksdb", "scavenger", "scavenger_adaptive")
 

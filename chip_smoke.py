@@ -51,7 +51,8 @@ ADAPTIVE_UPDATES = 2        # its zipfian updates per loaded record
 SERVE_REQUESTS, SERVE_NEW = 4, 16
 SERVE_REF_LEN = 64          # padded length of the greedy reference pass
 LOGIT_TOL = 0.05            # top-2 logit gap under which bf16 may flip
-KERNEL_OPS = ("lookup_probe", "run_coalesce", "segment_reduce")
+KERNEL_OPS = ("rank_probe", "interval_rank", "lookup_probe", "run_coalesce",
+              "segment_reduce")
 
 
 def log(msg: str) -> None:

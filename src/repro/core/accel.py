@@ -16,14 +16,16 @@ Every routed call returns ``None`` when it declines (kernels off, batch
 too small or too large for the structure, or keys outside the u32
 dictionary-encoding range) — callers fall back to the host path, which
 produces the same bytes.  Wall-clock spent inside routed ops is emitted
-to the observer as a ``kernel_<op>_us`` histogram per fused op class
-(real host microseconds, not simulated time — the one obs metric
-measured on the wall clock).
+to the observer as a ``kernel_<op>_us`` histogram named for the kernel
+op the call runs (real host microseconds, not simulated time).  A
+structure column that is not yet on the device is padded and handed to
+it inside that interval, in a ``device.upload`` phase span.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 
 import numpy as np
@@ -89,6 +91,13 @@ def _fits_u32(*arrays) -> bool:
     return True
 
 
+def _upload(store):
+    """The ``upload`` scope a routed call hands the kernel ops: a
+    ``device.upload`` phase span, opened only on a device-cache miss."""
+    return functools.partial(store.obs.span, store, "device.upload",
+                             store.io.lane)
+
+
 def _emit(store, opclass: str, t0: float) -> None:
     store.obs.on_op(store, f"kernel_{opclass}_us",
                     (time.perf_counter() - t0) * 1e6)
@@ -120,8 +129,9 @@ def memtable_probe(store, mt, keys):
         return None
     from repro import kernels
     t0 = time.perf_counter()
-    found, rank = kernels.rank_probe(keys, mk, mode=pol.mode)
-    _emit(store, "lookup_probe", t0)
+    found, rank = kernels.rank_probe(keys, mk, mode=pol.mode,
+                                     upload=_upload(store))
+    _emit(store, "rank_probe", t0)
     safe = np.where(rank < n, rank, 0)   # host get_batch's gather guard
     return (found, seqs[safe], ety[safe], vids[safe], vsz[safe], vf[safe])
 
@@ -146,7 +156,8 @@ def table_probe(store, t, keys, kraw):
     # pass the stable u64 backing words: ops caches the padded device copy
     # against this array's identity (a .view here would defeat the cache)
     may, found, rank = kernels.lookup_probe(keys, t.keys, bit_idx, bf.bits,
-                                            mode=pol.mode)
+                                            mode=pol.mode,
+                                            upload=_upload(store))
     _emit(store, "lookup_probe", t0)
     return may, np.where(found, rank, -1)
 
@@ -162,8 +173,9 @@ def assign_files(store, lvl: int, keys):
         return None
     from repro import kernels
     t0 = time.perf_counter()
-    fidx = kernels.interval_rank(keys, mins, maxs, mode=pol.mode)
-    _emit(store, "lookup_probe", t0)
+    fidx = kernels.interval_rank(keys, mins, maxs, mode=pol.mode,
+                                 upload=_upload(store))
+    _emit(store, "interval_rank", t0)
     return fidx
 
 
@@ -178,8 +190,9 @@ def table_find(store, t, keys):
         return None
     from repro import kernels
     t0 = time.perf_counter()
-    found, rank = kernels.rank_probe(keys, t.keys, mode=pol.mode)
-    _emit(store, "lookup_probe", t0)
+    found, rank = kernels.rank_probe(keys, t.keys, mode=pol.mode,
+                                     upload=_upload(store))
+    _emit(store, "rank_probe", t0)
     return np.where(found, rank, -1)
 
 

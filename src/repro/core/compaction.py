@@ -153,48 +153,52 @@ def run_compaction(store, level: int, base_level: int) -> None:
     downs = v.overlapping(out_level, lo, hi)
     inputs = ups + downs
     drop_tomb = out_level == last
-    kept, dropped = _merge_inputs(store, inputs, drop_tomb)
+    span, lane = store.obs.span, store.io.lane
+    with span(store, "compact.merge", lane):
+        kept, dropped = _merge_inputs(store, inputs, drop_tomb)
 
-    # ---- I/O ----
-    in_bytes = sum(t.file_bytes for t in inputs)
-    if cfg.readahead_compaction:
-        store.io.seq_read(in_bytes, sio.CAT_COMPACT_READ)
-    else:
-        for t in inputs:
-            for b in range(t.n_data_blocks):
-                store.io.rand_read(cfg.block_size, sio.CAT_COMPACT_READ)
+        # ---- I/O ----
+        in_bytes = sum(t.file_bytes for t in inputs)
+        if cfg.readahead_compaction:
+            store.io.seq_read(in_bytes, sio.CAT_COMPACT_READ)
+        else:
+            for t in inputs:
+                for b in range(t.n_data_blocks):
+                    store.io.rand_read(cfg.block_size, sio.CAT_COMPACT_READ)
 
-    # ---- engine hook: compaction-triggered relocation (BlobDB) ----
-    kept = store.strategy.on_compaction_kept(store, kept)
+        # ---- engine hook: compaction-triggered relocation (BlobDB) ----
+        kept = store.strategy.on_compaction_kept(store, kept)
 
-    outs = _cut_outputs(store, kept)
-    for t in outs:
-        store.io.seq_write(t.file_bytes, sio.CAT_COMPACT_WRITE)
+    with span(store, "compact.cut", lane):
+        outs = _cut_outputs(store, kept)
+        for t in outs:
+            store.io.seq_write(t.file_bytes, sio.CAT_COMPACT_WRITE)
     store._crashpoint("mid_compaction")   # outputs written, version not yet
     #                                       updated (DESIGN.md §9)
 
-    # ---- version update ----
-    if level == 0:
-        v.levels[0] = []
-    else:
-        v.levels[level] = [t for t in v.levels[level] if t not in ups]
-        v._bounds_cache.pop(level, None)
-    remain = [t for t in v.levels[out_level] if t not in downs]
-    v.set_level(out_level, remain + outs)
-    for t in inputs:
-        store.cache.erase_file(t.fid)
-    if store.durability is not None:
+    with span(store, "compact.install", lane):
+        # ---- version update ----
+        if level == 0:
+            v.levels[0] = []
+        else:
+            v.levels[level] = [t for t in v.levels[level] if t not in ups]
+            v._bounds_cache.pop(level, None)
+        remain = [t for t in v.levels[out_level] if t not in downs]
+        v.set_level(out_level, remain + outs)
         for t in inputs:
-            store._log_edit("drop_file", fid=t.fid)
-        for t in outs:
-            store._log_edit("add_file", fid=t.fid, level=out_level,
-                            nbytes=t.file_bytes)
+            store.cache.erase_file(t.fid)
+        if store.durability is not None:
+            for t in inputs:
+                store._log_edit("drop_file", fid=t.fid)
+            for t in outs:
+                store._log_edit("add_file", fid=t.fid, level=out_level,
+                                nbytes=t.file_bytes)
 
-    # ---- garbage exposure + DropCache (paper §II-D, §III-B.3) ----
-    dk, de, dvid, dvsz, dvf = dropped
-    store.expose_garbage(dk, de, dvid, dvsz, dvf)
-    if cfg.hotcold_write and len(dk):
-        store.dropcache.record(dk)
+        # ---- garbage exposure + DropCache (paper §II-D, §III-B.3) ----
+        dk, de, dvid, dvsz, dvf = dropped
+        store.expose_garbage(dk, de, dvid, dvsz, dvf)
+        if cfg.hotcold_write and len(dk):
+            store.dropcache.record(dk)
     store.n_compactions += 1
 
 

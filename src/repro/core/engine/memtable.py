@@ -56,6 +56,12 @@ class Memtable:
     def get(self, key: int):
         return self.entries.get(key)
 
+    @property
+    def snapshot_current(self) -> bool:
+        """True while the cached snapshot matches the entries (no write
+        since it was built): ``snapshot()`` then rebuilds nothing."""
+        return self._snap is not None
+
     def snapshot(self) -> tuple:
         """Key-sorted columnar view: (keys, seqs, etype, vids, vsizes,
         vfiles) parallel arrays, cached until the next write."""

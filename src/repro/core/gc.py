@@ -70,42 +70,48 @@ def has_pending(store, threshold: float) -> bool:
 
 def run_gc(store, candidates: list[SSTable]) -> None:
     strat = store.strategy
+    span, lane = store.obs.span, store.io.lane
     store.in_gc = True
     try:
         # ---------------------------------------------------- 1. Read phase
-        for t in candidates:
-            strat.gc_read_candidate(store, t)
+        with span(store, "gc.read", lane):
+            for t in candidates:
+                strat.gc_read_candidate(store, t)
 
         # ------------------------------------------------ 2. GC-Lookup phase
-        all_keys = np.concatenate([t.keys for t in candidates])
-        all_vids = np.concatenate([t.vids for t in candidates])
-        all_vsz = np.concatenate([t.vsizes for t in candidates])
-        cand_of = np.concatenate([np.full(t.n, i, np.int64)
-                                  for i, t in enumerate(candidates)])
-        res = store.lookup_entries(all_keys, sio.CAT_GC_LOOKUP)
+        with span(store, "gc.lookup", lane):
+            all_keys = np.concatenate([t.keys for t in candidates])
+            all_vids = np.concatenate([t.vids for t in candidates])
+            all_vsz = np.concatenate([t.vsizes for t in candidates])
+            cand_of = np.concatenate([np.full(t.n, i, np.int64)
+                                      for i, t in enumerate(candidates)])
+            res = store.lookup_entries(all_keys, sio.CAT_GC_LOOKUP)
 
-        valid = res["found"] & (res["etype"] == ETYPE_REF) & \
-            (res["vid"] == all_vids)
-        valid = strat.gc_refine_valid(store, candidates, cand_of, res,
-                                      all_keys, all_vids, valid)
+            valid = res["found"] & (res["etype"] == ETYPE_REF) & \
+                (res["vid"] == all_vids)
+            valid = strat.gc_refine_valid(store, candidates, cand_of, res,
+                                          all_keys, all_vids, valid)
 
         # ------------------------------------- 3. lazy value read (Scavenger)
-        strat.gc_value_read(store, candidates, cand_of, valid)
+        with span(store, "gc.value_read", lane):
+            strat.gc_value_read(store, candidates, cand_of, valid)
 
         # ---------------------------------------------------- 4. Write phase
-        vkeys = all_keys[valid]
-        vvids = all_vids[valid]
-        vvsz = all_vsz[valid]
-        order = np.argsort(vkeys, kind="stable")
-        vkeys, vvids, vvsz = vkeys[order], vvids[order], vvsz[order]
-        new_files, new_fid_per_rec = store.build_value_files(
-            vkeys, vvids, vvsz, sio.CAT_GC_WRITE)
+        with span(store, "gc.write", lane):
+            vkeys = all_keys[valid]
+            vvids = all_vids[valid]
+            vvsz = all_vsz[valid]
+            order = np.argsort(vkeys, kind="stable")
+            vkeys, vvids, vvsz = vkeys[order], vvids[order], vvsz[order]
+            new_files, new_fid_per_rec = store.build_value_files(
+                vkeys, vvids, vvsz, sio.CAT_GC_WRITE)
         store._crashpoint("gc_pre_chain")    # outputs written, chains /
         #                                      registry not yet updated
 
         # --------------------------------- 5. retire candidates / writeback
-        strat.gc_finalize(store, candidates, new_files, vkeys, vvids, vvsz,
-                          new_fid_per_rec)
+        with span(store, "gc.finalize", lane):
+            strat.gc_finalize(store, candidates, new_files, vkeys, vvids,
+                              vvsz, new_fid_per_rec)
         store._crashpoint("gc_post_chain")   # chain update durable in the
         #                                      MANIFEST, run counter not yet
 
@@ -117,7 +123,6 @@ def run_gc(store, candidates: list[SSTable]) -> None:
         # rewrite/reclaim bytes per GC run is the paper's Fig.3 axis
         store.obs.on_op(store, "gc_rewrite_bytes", rewrite)
         store.obs.on_op(store, "gc_reclaimed_bytes", reclaimed)
-        store.obs.on_op(store, "gc_input_files", len(candidates))
         # space-event ledger (§13): rewrite/reclaim bytes by cause
         store.obs.on_space(store, "gc_rewrite", rewrite)
         store.obs.on_space(store, "gc_reclaim", reclaimed)

@@ -73,25 +73,39 @@ def lookup_entries(store, keys: np.ndarray, cat: str) -> dict:
         "vfile": np.full(n, -1, np.int64),
     }
     unresolved = np.ones(n, bool)
+    obs, lane = store.obs, store.io.lane
 
     # ---- memtables, newest first: batched columnar probes ----
-    for mt in [store.memtable] + list(reversed(store.immutables)):
-        if not unresolved.any():
-            break
-        rows = np.nonzero(unresolved)[0]
-        probe = accel.memtable_probe(store, mt, keys[rows])
-        found, _, ety, vids, vsz, vf = (probe if probe is not None
-                                        else mt.get_batch(keys[rows]))
-        if not found.any():
-            continue
-        hit = rows[found]
-        out["found"][hit] = True
-        out["etype"][hit] = ety[found]
-        out["vid"][hit] = vids[found]
-        out["vsize"][hit] = vsz[found]
-        out["vfile"][hit] = vf[found]
-        unresolved[hit] = False
+    with obs.span(store, "read.memtable", lane):
+        for mt in [store.memtable] + list(reversed(store.immutables)):
+            if not unresolved.any():
+                break
+            if not mt.snapshot_current:
+                with obs.span(store, "memtable.snapshot", lane):
+                    mt.snapshot()
+            rows = np.nonzero(unresolved)[0]
+            probe = accel.memtable_probe(store, mt, keys[rows])
+            found, _, ety, vids, vsz, vf = (probe if probe is not None
+                                            else mt.get_batch(keys[rows]))
+            if not found.any():
+                continue
+            hit = rows[found]
+            out["found"][hit] = True
+            out["etype"][hit] = ety[found]
+            out["vid"][hit] = vids[found]
+            out["vsize"][hit] = vsz[found]
+            out["vfile"][hit] = vf[found]
+            unresolved[hit] = False
 
+    with obs.span(store, "read.tables", lane):
+        _probe_tables(store, keys, cat, out, unresolved)
+    return out
+
+
+def _probe_tables(store, keys: np.ndarray, cat: str, out: dict,
+                  unresolved: np.ndarray) -> None:
+    """L0 newest first, then L1..Ln by file assignment: fill ``out`` for
+    the ``unresolved`` rows (cleared as they resolve)."""
     # raw bloom hashes depend only on the key column: hash once, reuse
     # against every probed table's filter
     kraw = hash_family(keys, BloomFilter.k_for(store.cfg.filter_bits_per_key))
@@ -133,4 +147,3 @@ def lookup_entries(store, keys: np.ndarray, cat: str) -> dict:
             fidx = store.version.assign_files(lvl, keys[rows])
         for fi in np.unique(fidx[fidx >= 0]):
             probe_file(files[fi], rows[fidx == fi])
-    return out

@@ -184,8 +184,6 @@ class Store(ScalarOps):
             # engines, costs no simulated time)
             self.strategy.observe_batch(self, "write", keys, vsz)
             self._after_write(total)
-        self.obs.on_op(self, "put_batch_n", n)
-        self.obs.on_op(self, "put_batch_bytes", total)
         self.obs.tick(self)
         return vids_out
 
@@ -255,8 +253,6 @@ class Store(ScalarOps):
             self.latest.apply_batch(is_put, keys, use_vids, vsz)
             self.strategy.observe_batch(self, "write", keys, vsz)
             self._after_write(total)
-        self.obs.on_op(self, "ingest_batch_n", n)
-        self.obs.on_op(self, "ingest_batch_bytes", total)
         self.obs.tick(self)
 
     # -------------------------------------------------------- batched reads
@@ -283,13 +279,13 @@ class Store(ScalarOps):
             live = res["found"] & (res["etype"] != ETYPE_TOMB)
             refs = np.nonzero(live & (res["etype"] == ETYPE_REF))[0]
             if len(refs):
-                self._read_values_batch(keys[refs], res["vid"][refs],
-                                        res["vfile"][refs],
-                                        res["vsize"][refs], sio.CAT_FG_READ,
-                                        strict=True)
+                with self.obs.span(self, "read.values", self.io.lane):
+                    self._read_values_batch(keys[refs], res["vid"][refs],
+                                            res["vfile"][refs],
+                                            res["vsize"][refs],
+                                            sio.CAT_FG_READ, strict=True)
         self.strategy.observe_batch(self, "read", keys)
         self.pump()
-        self.obs.on_op(self, "get_batch_n", n)
         self.obs.tick(self)
         return {"found": live,
                 "vid": np.where(live, res["vid"], 0).astype(np.uint64),
@@ -320,29 +316,32 @@ class Store(ScalarOps):
     # ===================================================== background lanes
     def next_compact_job(self):
         """Work-finder for the flush/compaction pool (16 threads)."""
-        if self.immutables:
-            return ("flush",)
-        pick = comp.pick_compaction(self)
-        if pick is not None:
-            return ("compact", pick)
-        return None
+        with self.obs.span(self, "job.pick", "bg"):
+            if self.immutables:
+                return ("flush",)
+            pick = comp.pick_compaction(self)
+            if pick is not None:
+                return ("compact", pick)
+            return None
 
     def next_gc_job(self):
         """Work-finder for the dedicated GC pool (1-2 threads — Titan/
         TerarkDB defaults; GC lags ingest, which is the source of the
         paper's space-amplification backlog)."""
-        if not self.strategy.wants_standalone_gc():
+        with self.obs.span(self, "job.pick", "gc"):
+            if not self.strategy.wants_standalone_gc():
+                return None
+            if self.in_batch_write:
+                # A WriteBatch applies atomically over one preassigned seq
+                # range; GC (whose Titan writebacks mint fresh seqs) must
+                # not interleave with it or a written-back locator could
+                # outrank a not-yet-inserted batch record.  GC resumes at
+                # batch end.
+                return None
+            cands = gcmod.gc_candidates(self, self._gc_threshold())
+            if cands:
+                return ("gc", gcmod.gc_batch(self, cands))
             return None
-        if self.in_batch_write:
-            # A WriteBatch applies atomically over one preassigned seq
-            # range; GC (whose Titan writebacks mint fresh seqs) must not
-            # interleave with it or a written-back locator could outrank a
-            # not-yet-inserted batch record.  GC resumes at batch end.
-            return None
-        cands = gcmod.gc_candidates(self, self._gc_threshold())
-        if cands:
-            return ("gc", gcmod.gc_batch(self, cands))
-        return None
 
     def _job_pick(self, kind: str) -> str:
         """Policy decision that selects work of this kind (ledger §13)."""

@@ -17,6 +17,7 @@ from gather-free primitives:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import weakref
 
@@ -61,16 +62,19 @@ def interpret_default() -> bool:
 _DEVICE_CACHE: dict = {}
 
 
-def device_cached(host_arr: np.ndarray, tag: str, build):
+def device_cached(host_arr: np.ndarray, tag: str, build, upload=None):
     """``build()``'s device array, cached under ``(id(host_arr), tag)``.
 
     The host array must be treated as immutable by the caller — the cache
-    returns the stale device copy otherwise."""
+    returns the stale device copy otherwise.  ``upload``, where given, is
+    called on a miss for the context manager the build runs in (the
+    caller's scope for a column handed to the device)."""
     key = (id(host_arr), tag)
     ent = _DEVICE_CACHE.get(key)
     if ent is not None and ent[0]() is host_arr:
         return ent[1]
-    dev = build()
+    with (upload or contextlib.nullcontext)():
+        dev = build()
     _DEVICE_CACHE[key] = (weakref.ref(host_arr), dev)
     weakref.finalize(host_arr, _DEVICE_CACHE.pop, key, None)
     return dev

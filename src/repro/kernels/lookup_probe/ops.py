@@ -55,17 +55,17 @@ def _pad_q(a, qp) -> np.ndarray:
     return out
 
 
-def _run_dev(run: np.ndarray, fill, tag: str):
+def _run_dev(run: np.ndarray, fill, tag: str, upload=None):
     """Cached padded device copy of an immutable sorted key column."""
     def build():
         n = run.shape[0]
         p = np.full(column_len(n), fill, np.uint32)
         p[:n] = run
         return jnp.asarray(p)
-    return device_cached(run, tag, build)
+    return device_cached(run, tag, build, upload)
 
 
-def _words_dev(words: np.ndarray):
+def _words_dev(words: np.ndarray, upload=None):
     """Cached padded device copy of an immutable filter-word column
     (accepts the engine's u64 backing words or raw u32)."""
     def build():
@@ -74,16 +74,19 @@ def _words_dev(words: np.ndarray):
         p = np.zeros(column_len(w.shape[0]), np.uint32)
         p[:w.shape[0]] = w
         return jnp.asarray(p)
-    return device_cached(words, "words", build)
+    return device_cached(words, "words", build, upload)
 
 
-def lookup_probe(queries, table_keys, bit_idx, words, *, mode=None):
+def lookup_probe(queries, table_keys, bit_idx, words, *, mode=None,
+                 upload=None):
     """Fused bloom + membership/rank probe of one SSTable.
 
     queries (Q,) and sorted unique table_keys (N,) key columns (u32, or
     u64 that fits); bit_idx (Q, k) u32 pre-modulo'd bloom bit indices;
     words (W,) u32 (or the backing u64) filter words.  -> numpy (may (Q,)
-    bool, found (Q,) bool, rank (Q,) i64), rank = searchsorted-left."""
+    bool, found (Q,) bool, rank (Q,) i64), rank = searchsorted-left.
+    ``upload``: ``common.device_cached``'s scope for a column it uploads
+    (here and in ``rank_probe`` / ``interval_rank``)."""
     if mode is None:
         mode = resolve_mode(None)
     queries = _check_u32(queries)
@@ -96,8 +99,8 @@ def lookup_probe(queries, table_keys, bit_idx, words, *, mode=None):
     qs = _pad_q(queries, qp)
     bi = np.zeros((qp, k), np.uint32)
     bi[:q] = bit_idx
-    tk = _run_dev(table_keys, U32_TABLE_PAD, "run")
-    ws = _words_dev(np.asarray(words))
+    tk = _run_dev(table_keys, U32_TABLE_PAD, "run", upload)
+    ws = _words_dev(np.asarray(words), upload)
     if mode == "xla":
         may, found, rank = _xla_lookup(qs, tk, bi, ws)
     else:
@@ -109,7 +112,7 @@ def lookup_probe(queries, table_keys, bit_idx, words, *, mode=None):
             np.asarray(rank)[:q].astype(np.int64))
 
 
-def rank_probe(queries, table_keys, *, mode=None):
+def rank_probe(queries, table_keys, *, mode=None, upload=None):
     """Membership/rank probe without a filter (memtable snapshots).
     -> numpy (found (Q,) bool, rank (Q,) i64)."""
     if mode is None:
@@ -121,7 +124,7 @@ def rank_probe(queries, table_keys, *, mode=None):
         return np.zeros(0, bool), np.zeros(0, np.int64)
     qp = round_up(max(QUERY_TILE, next_pow2(q)), QUERY_TILE)
     qs = _pad_q(queries, qp)
-    tk = _run_dev(table_keys, U32_TABLE_PAD, "run")
+    tk = _run_dev(table_keys, U32_TABLE_PAD, "run", upload)
     if mode == "xla":
         found, rank = _xla_rank(qs, tk)
     else:
@@ -132,7 +135,7 @@ def rank_probe(queries, table_keys, *, mode=None):
             np.asarray(rank)[:q].astype(np.int64))
 
 
-def interval_rank(queries, mins, maxs, *, mode=None):
+def interval_rank(queries, mins, maxs, *, mode=None, upload=None):
     """Index of the covering [min, max] interval per query; -1 if none.
 
     ``mins`` sorted ascending, intervals disjoint (an LSM level's file
@@ -148,7 +151,7 @@ def interval_rank(queries, mins, maxs, *, mode=None):
     qp = round_up(max(QUERY_TILE, next_pow2(q)), QUERY_TILE)
     qs = _pad_q(queries, qp)
     # all-ones pad is > any real query, so padded mins never count as <=
-    ms = _run_dev(mins, U32_MAX, "mins")
+    ms = _run_dev(mins, U32_MAX, "mins", upload)
     if mode == "xla":
         cnt = _xla_count(qs, ms)
     else:

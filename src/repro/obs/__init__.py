@@ -30,12 +30,12 @@ from .health import HealthSampler, sample_store
 from .ledger import (AmplificationLedger, blame_rows, cause_key,
                      check_conservation, live_breakdown, parse_cause)
 from .metrics import Counter, Gauge, LogHist, MetricsRegistry
-from .observer import NULL_OBSERVER, NullObserver, Observer
+from .observer import NULL_OBSERVER, PHASES, NullObserver, Observer
 from .trace import SpanTracer, chrome_trace, dump_chrome_trace
 
 __all__ = ["AmplificationLedger", "Causality", "Counter", "Frame", "Gauge",
            "HealthSampler", "LogHist", "MetricsRegistry", "NULL_OBSERVER",
-           "NullObserver", "Observer", "SpanTracer", "blame_rows",
+           "NullObserver", "Observer", "PHASES", "SpanTracer", "blame_rows",
            "cause_key", "check_conservation", "chrome_trace",
            "dump_chrome_trace", "live_breakdown", "parse_cause",
            "sample_store"]

@@ -35,6 +35,17 @@ from .trace import DEFAULT_CAP, SpanTracer, dump_chrome_trace
 
 _NULL_CTX = contextlib.nullcontext()
 
+# Phase spans: the steps inside a foreground op or a background job, one
+# span per phase per call.  They are timed only by observers that keep a
+# host clock; ``Observer`` hands back the null context for them, so they
+# never become simulated-lane spans, cause frames or ledger scopes.
+PHASES = frozenset({
+    "read.memtable", "memtable.snapshot", "read.tables", "read.values",
+    "device.upload", "job.pick",
+    "gc.read", "gc.lookup", "gc.value_read", "gc.write", "gc.finalize",
+    "compact.merge", "compact.cut", "compact.install",
+})
+
 # Byte/op counter fields snapshotted around a span to attach per-category
 # payload deltas (names mirror SimIO's counters).
 _IO_FIELDS = ("read_bytes", "write_bytes", "read_ops", "write_ops")
@@ -179,6 +190,8 @@ class Observer(NullObserver):
 
     # ---------------------------------------------------------------- spans
     def span(self, store, name, lane="fg", **args):
+        if name in PHASES:
+            return _NULL_CTX
         return _Span(self, store, name, lane, args)
 
     def _begin_span(self, store, name, lane, args):

@@ -115,7 +115,8 @@ def test_kernel_us_histograms_reach_observer():
         store.multi_get(rng.integers(0, N_KEYS, 192).astype(np.uint64))
     store.drain()
     store.multi_get(np.arange(N_KEYS, dtype=np.uint64))
-    for op in ("lookup_probe", "run_coalesce", "segment_reduce"):
+    for op in ("rank_probe", "interval_rank", "lookup_probe", "run_coalesce",
+               "segment_reduce"):
         h = obs.metrics.merged(f"kernel_{op}_us")
         assert h.count > 0, f"no kernel_{op}_us samples"
         assert h.vmax < 60e6        # sanity: wall-clock us, not ns
