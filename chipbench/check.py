@@ -2,15 +2,17 @@
 
 Every answer of the run is checked against the plain reference
 (``reference.LastWriteWins``), replayed in the order the client sent the
-requests: the set-up writes, the warm-up and window requests, then the
-read-back after the window.  Each number compared is a count of wrong
-answers, exact, with the limit 0:
+requests: the set-up writes, the warm-up and window requests, the
+untimed requests after the window, then the read-back.  Each number
+compared is a count of wrong answers, exact, with the limit 0:
 
   ack_bad        acknowledged vids that are 0 or repeat an earlier one
   read_wrong     keys a ``multi_get`` answered otherwise than the
                  reference (found, vid and value size)
   scan_wrong     ``multi_scan`` results that differ from the reference's
   readback_wrong keys read back after the window that differ
+
+Requests after the window (``n_timed`` on) count in no ``failed``.
 
 The reference takes each write's vids as the store acknowledged them, so
 a read that misses an acknowledged write, an answer altered where it is
@@ -53,13 +55,17 @@ def _scan_wrong(ref, req, answer) -> int:
 
 
 def check_run(ref, setup_batches, acked, done, n_warm, readback,
-              space0) -> CheckResult:
+              space0, n_timed=None) -> CheckResult:
+    """``space0``: stored bytes after set-up (None: no space statistic);
+    ``ratios`` then holds stored over live bytes before the first request
+    and after each one."""
     counts = dict.fromkeys(LIMITS, 0)
     seen_vids = []
     for (keys, sizes), vids in zip(setup_batches, acked):
         ref.apply(keys, vids, sizes)
         seen_vids.append(np.asarray(vids, np.uint64))
     ratios = [] if space0 is None else [space0 / max(ref.live_bytes, 1)]
+    timed_end = len(done) if n_timed is None else n_timed
     failed = 0
     for i, d in enumerate(done):
         r = d.req
@@ -78,10 +84,10 @@ def check_run(ref, setup_batches, acked, done, n_warm, readback,
         else:
             bad = _scan_wrong(ref, r, d.answer)
             counts["scan_wrong"] += bad
-        if i >= n_warm:
+        if n_warm <= i < timed_end:
             failed += bad > 0
-            if space0 is not None:
-                ratios.append(d.space / max(ref.live_bytes, 1))
+        if space0 is not None:
+            ratios.append(d.space / max(ref.live_bytes, 1))
     allv = np.concatenate(seen_vids) if seen_vids else np.zeros(0, np.uint64)
     dup = len(allv) - len(np.unique(allv))
     counts["ack_bad"] += int((allv == 0).sum()) + dup

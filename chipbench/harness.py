@@ -15,9 +15,12 @@ A run makes its data from the seed, builds the store through
 a new executable for ``WARM_QUIET_S`` seconds (the background jobs that
 writes set off build theirs too), then one client sends requests back to
 back for the window.
-Once the window has closed it reads the device's peak memory, reads back
-a sample of the store (cells that write in the window), frees the store
-and checks every answer of the run against ``reference.LastWriteWins``.
+Once the window has closed it reads the device's peak memory.  An
+untraced run of a cell with a space statistic then sends the stream's
+next requests, untimed, until warm-up, window and these have written the
+traffic's ``space_volume`` times the configuration's logical bytes.  It
+reads back a sample of the store (cells that write), frees the store and
+checks every answer of the run against ``reference.LastWriteWins``.
 """
 
 from __future__ import annotations
@@ -191,6 +194,13 @@ def make_store(cfg: dict, observer=None):
     return Store(ec)
 
 
+def user_bytes(req: tgen.Request, key_bytes: int) -> int:
+    """User bytes a request writes: key plus value bytes per record."""
+    if req.kind != "write":
+        return 0
+    return int((key_bytes + req.sizes).sum())
+
+
 def stored_bytes(store) -> int:
     """The benchmark's own sum of the version's kSST and vSST sizes."""
     v = store.version
@@ -256,11 +266,11 @@ def _pct(vals, q) -> float:
 
 
 def end_to_end(cell: Cell, window: list, t0: float, t1: float,
-               space_series, setup_s: float) -> dict:
+               space: float | None, setup_s: float) -> dict:
     """Each end-to-end metric by the statistic its file
     ``chipbench/end_to_end/<name>.json`` names: ``rate`` (ops over the
     window's seconds), ``p95_ms`` (of the requests of the listed kinds),
-    ``space_amp`` or ``setup``."""
+    ``space_amp`` (``byte_weighted``) or ``setup``."""
     out = {}
     for m in cell.end_to_end:
         spec = e2e_spec(cell.bench_dir, m["name"])
@@ -273,19 +283,34 @@ def end_to_end(cell: Cell, window: list, t0: float, t1: float,
             v = _pct([(d.t1 - d.t0) * 1e3 for d in window
                       if d.req.kind in spec["kinds"]], 95)
         elif stat == SPACE_STAT:
-            v = space_series
+            v = space
         else:
             raise ValueError(f"{m['name']}: no statistic named {stat!r}")
         out[m["name"]] = {"value": v, "unit": m["unit"]}
     return out
 
 
-def readback_keys(window: list, records: int, rng) -> list:
-    """Keys to read back after a window that wrote: the newest written
-    keys, a sample of all keys the window wrote, and a sample of the whole
-    key space (records the window never touched, moved by GC and
-    compaction)."""
-    wrote = [d.req.keys for d in window if d.req.kind == "write"]
+def byte_weighted(ratios: list, done: list, key_bytes: int,
+                  volume: float) -> float:
+    """Mean of stored over live bytes across the first ``volume`` user
+    bytes written after set-up: each write request's bytes at the ratio in
+    force before it (``ratios[k]`` before ``done[k]``), the last request
+    cut at ``volume``."""
+    total, left = 0.0, volume
+    for r, d in zip(ratios, done):
+        w = min(user_bytes(d.req, key_bytes), left)
+        total += r * w
+        left -= w
+        if left <= 0:
+            break
+    return total / volume
+
+
+def readback_keys(sent: list, records: int, rng) -> list:
+    """Keys to read back after requests that wrote: the newest written
+    keys, a sample of all keys they wrote, and a sample of the whole key
+    space (records they never touched, moved by GC and compaction)."""
+    wrote = [d.req.keys for d in sent if d.req.kind == "write"]
     if not wrote:
         return []
     allw = np.concatenate(wrote)
@@ -339,6 +364,9 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
     obs = WallObserver() if trace else None
     store = (store_factory or make_store)(cfg, obs)
     acked = [store.write(WriteBatch().puts(k, s)) for k, s in setup.batches()]
+    space_cell = any(e2e_spec(cell.bench_dir, m["name"])["stat"] == SPACE_STAT
+                     for m in cell.end_to_end)
+    space0 = stored_bytes(store) if space_cell else 0
     t_warm = time.perf_counter()
     done: list[Done] = []
     i, t_built = 0, t_warm
@@ -348,19 +376,25 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
         t0 = time.perf_counter()
         ans = send(store, stream[i], batches[i])
         done.append(Done(stream[i], t0, time.perf_counter(), ans))
+        if space_cell:
+            done[-1].space = stored_bytes(store)
         if compiles.n != c0:
             t_built = done[-1].t1
         i += 1
     n_warm = i
-    space_cell = any(e2e_spec(cell.bench_dir, m["name"])["stat"] == SPACE_STAT
-                     for m in cell.end_to_end)
+
+    def at(k: int) -> int:
+        # past the end of the stream the window's requests come round
+        # again, in the same order
+        return k if k < len(stream) else n_warm + (k - n_warm) % (
+            len(stream) - n_warm)
+
     profile_dir = None
     if trace:
         from . import trace as tr
         profile_dir = tr.start(cell.bench_dir)
         obs.active = True
     dev_w0, imm0 = background_state(store)
-    space0 = stored_bytes(store) if space_cell else 0
     c_window = compiles.n
     t_window = time.perf_counter()
     setup_s = t_window - t_process
@@ -373,10 +407,7 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
         t0 = time.perf_counter()
         if t0 >= end:
             break
-        # past the end of the stream the window's requests come round
-        # again, in the same order
-        j = i if i < len(stream) else n_warm + (i - n_warm) % (
-            len(stream) - n_warm)
+        j = at(i)
         if trace:
             with TraceAnnotation(f"client:{stream[j].op}"):
                 ans = send(store, stream[j], batches[j])
@@ -411,13 +442,31 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
         f"{in_window} executables built inside the window in "
         f"{sum(compiles.seconds[c_window:]):.3f} s "
         f"{compiles.names[c_window:]}")
-    user_w = sum(int((cfg["engine_config"]["key_bytes"] + d.req.sizes).sum())
-                 for d in window if d.req.kind == "write")
+    key_bytes = cfg["engine_config"]["key_bytes"]
+    user_w = sum(user_bytes(d.req, key_bytes) for d in window)
     log(f"window background: {dev_w1 - dev_w0} device bytes written for "
         f"{user_w} user bytes; immutable memtables {imm0} -> {imm1}")
+    n_timed = len(done)
+    if space_cell and not trace:
+        # untimed: the same request sequence a faster run saw, until the
+        # space statistic's volume of user bytes is written
+        volume = cell.traffic["space_volume"] * logical_bytes(cfg)
+        written = timed_w = sum(user_bytes(d.req, key_bytes) for d in done)
+        t_after = time.perf_counter()
+        while written < volume:
+            j = at(i)
+            t0 = time.perf_counter()
+            ans = send(store, stream[j], batches[j])
+            done.append(Done(stream[j], t0, time.perf_counter(), ans,
+                             stored_bytes(store)))
+            written += user_bytes(stream[j], key_bytes)
+            i += 1
+        log(f"space volume {volume:.0f} user bytes: warm-up and window "
+            f"wrote {timed_w}; after the window {len(done) - n_timed} "
+            f"untimed requests in {time.perf_counter() - t_after:.3f} s")
     rb_rng = np.random.default_rng([seed, 1])
     readback = []
-    for keys in readback_keys(window, cfg["records"], rb_rng):
+    for keys in readback_keys(done[n_warm:], cfg["records"], rb_rng):
         r = store.multi_get(keys)
         readback.append((keys, (r["found"], r["vid"], r["vsize"])))
     run = None
@@ -428,9 +477,9 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
     del store, obs
     gc.collect()
     t_check = time.perf_counter()
-    ref = LastWriteWins(cfg["engine_config"]["key_bytes"])
+    ref = LastWriteWins(key_bytes)
     res = check_run(ref, list(setup.batches()), acked, done, n_warm,
-                    readback, space0 if space_cell else None)
+                    readback, space0 if space_cell else None, n_timed)
     log(f"check {time.perf_counter() - t_check:.3f} s")
     if trace:
         metrics = {}
@@ -441,8 +490,7 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
     else:
         space = None
         if space_cell:
-            space = _time_weighted(res.ratios, t_window,
-                                   [d.t1 for d in window], t_end)
+            space = byte_weighted(res.ratios, done, key_bytes, volume)
         metrics = end_to_end(cell, window, t_window, t_end, space, setup_s)
     device = dict(device, memory_peak_bytes=peak)
     if trace:
@@ -459,10 +507,3 @@ def _run(cell, cfg, device, seed, seconds, trace, t_process, rehearsal,
     print(json.dumps(result), file=out, flush=True)
     return result
 
-
-def _time_weighted(ratios, t0: float, ends: list, t_end: float) -> float:
-    """Mean over [t0, t_end] of a ratio that holds from one request's end
-    to the next one's: ``ratios[0]`` before the window, ``ratios[k]``
-    after its k-th request."""
-    w = np.diff(np.asarray([t0] + list(ends) + [t_end], np.float64))
-    return float((np.asarray(ratios, np.float64) * w).sum() / w.sum())
