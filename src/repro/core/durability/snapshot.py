@@ -28,14 +28,13 @@ import numpy as np
 
 from ..engine.config import EngineConfig
 from ..engine.io import DeviceModel, SimIO
-from ..engine.memtable import Memtable
+from ..engine.memtable import COLUMN_DTYPES, Memtable
 from ..engine.tables import KIND_VALUE, SSTable
 from .records import append_record, pack_array, scan_records, unpack_array
 
 FORMAT = 1
 
 _MT_COLS = ("keys", "seqs", "ety", "vids", "vsz", "vf")
-_MT_DTYPES = (np.uint64, np.uint64, np.uint8, np.uint64, np.int64, np.int64)
 _TBL_COLS = ("keys", "seqs", "etype", "vids", "vsizes", "vfiles")
 
 
@@ -46,7 +45,7 @@ def _memtable_arrays(mt: Memtable) -> dict[str, np.ndarray]:
     vals = list(mt.entries.values())
     cols = [keys] + [
         np.fromiter((v[i] for v in vals), dt, count=n)
-        for i, dt in enumerate(_MT_DTYPES[1:])]
+        for i, dt in enumerate(COLUMN_DTYPES[1:])]
     return dict(zip(_MT_COLS, cols))
 
 
@@ -215,6 +214,7 @@ def _restore_memtable(cfg, arrays, prefix: str) -> Memtable:
         mt.entries[k] = v
         total += mt._entry_bytes(v[1], v[3])
     mt.bytes = total
+    mt.drop_snapshot()
     return mt
 
 

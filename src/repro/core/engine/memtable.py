@@ -5,11 +5,18 @@ Entries are (seq, etype, vid, vsize, vfile).  Normal user puts are INLINE
 (the memtable holds the full value until flush decides separation); Titan's
 GC Write-Index puts REF entries pointing at an existing blob file.
 
-Reads go through a cached *columnar snapshot* (key-sorted parallel arrays,
-rebuilt lazily after a write): ``get_batch`` probes a whole key column with
-one ``searchsorted``, and scans slice key ranges out of the same arrays —
-no per-key Python in the batched read path.  Immutable memtables never
-rebuild; the active memtable rebuilds at most once per write batch.
+Reads go through a cached *columnar snapshot* (key-sorted parallel arrays):
+``get_batch`` probes a whole key column with one ``searchsorted``, and
+scans slice key ranges out of the same arrays — no per-key Python in the
+batched read path.  Writes append their records to a list of pending
+delta columns and leave the snapshot in place; the next ``snapshot()``
+merges the pending rows into it (a sort of the delta, a ``searchsorted``
+into the snapshot's keys, overwrites and ``np.insert``), so a read after
+a write costs the size of the write, not of the memtable.  Only a
+memtable whose ``entries`` were filled directly (a restore) rebuilds its
+snapshot from the dict.  A merge never writes into an array it handed
+out (device copies are cached on the host array's identity): it returns
+new columns, and keeps the key column itself when no key is new.
 """
 
 from __future__ import annotations
@@ -20,13 +27,22 @@ from .config import EngineConfig
 from .tables import ETYPE_INLINE, ETYPE_REF, ETYPE_TOMB
 
 
+# dtypes of the snapshot's columns: keys, seqs, etype, vids, vsizes, vfiles
+COLUMN_DTYPES = (np.uint64, np.uint64, np.uint8, np.uint64, np.int64,
+                 np.int64)
+
+
 class Memtable:
     def __init__(self, cfg: EngineConfig):
         self.cfg = cfg
         # key -> (seq, etype, vid, vsize, vfile)
         self.entries: dict[int, tuple] = {}
         self.bytes = 0
-        self._snap: tuple | None = None     # cached columnar snapshot
+        # columnar snapshot of ``entries`` less the pending rows; None
+        # after a restore filled ``entries`` directly
+        self._snap: tuple | None = tuple(np.empty(0, dt)
+                                         for dt in COLUMN_DTYPES)
+        self._pending: list[tuple] = []     # delta columns, arrival order
 
     def _entry_bytes(self, etype: int, vsize: int) -> int:
         if etype == ETYPE_TOMB:
@@ -41,7 +57,8 @@ class Memtable:
             self.bytes -= self._entry_bytes(prev[1], prev[3])
         self.entries[key] = entry
         self.bytes += self._entry_bytes(entry[1], entry[3])
-        self._snap = None
+        self._pending.append(tuple(
+            np.array([v], dt) for v, dt in zip((key, *entry), COLUMN_DTYPES)))
 
     def put(self, key: int, seq: int, vid: int, vsize: int) -> None:
         self._set(key, (seq, ETYPE_INLINE, vid, vsize, -1))
@@ -56,29 +73,68 @@ class Memtable:
     def get(self, key: int):
         return self.entries.get(key)
 
+    def drop_snapshot(self) -> None:
+        """Forget the snapshot after ``entries`` was filled directly: the
+        next ``snapshot()`` rebuilds it from the dict."""
+        self._snap = None
+        self._pending = []
+
     @property
     def snapshot_current(self) -> bool:
         """True while the cached snapshot matches the entries (no write
-        since it was built): ``snapshot()`` then rebuilds nothing."""
-        return self._snap is not None
+        since it was built): ``snapshot()`` then does nothing."""
+        return self._snap is not None and not self._pending
+
+    @property
+    def snapshot_merges(self) -> bool:
+        """True when ``snapshot()`` will merge pending rows into the cached
+        snapshot (False: nothing to do, or a rebuild from the dict)."""
+        return self._snap is not None and bool(self._pending)
 
     def snapshot(self) -> tuple:
         """Key-sorted columnar view: (keys, seqs, etype, vids, vsizes,
-        vfiles) parallel arrays, cached until the next write."""
+        vfiles) parallel arrays, with the pending writes merged in."""
         if self._snap is None:
+            self._pending = []
             n = len(self.entries)
             keys = np.fromiter(self.entries.keys(), np.uint64, count=n)
             order = np.argsort(keys, kind="stable")
             vals = list(self.entries.values())
-            self._snap = (
-                keys[order],
-                np.fromiter((v[0] for v in vals), np.uint64, count=n)[order],
-                np.fromiter((v[1] for v in vals), np.uint8, count=n)[order],
-                np.fromiter((v[2] for v in vals), np.uint64, count=n)[order],
-                np.fromiter((v[3] for v in vals), np.int64, count=n)[order],
-                np.fromiter((v[4] for v in vals), np.int64, count=n)[order],
-            )
+            self._snap = (keys[order],) + tuple(
+                np.fromiter((v[i] for v in vals), dt, count=n)[order]
+                for i, dt in enumerate(COLUMN_DTYPES[1:]))
+        elif self._pending:
+            self._snap = self._merge()
         return self._snap
+
+    def _merge(self) -> tuple:
+        """The snapshot with the pending rows merged in, last write per
+        key winning; new arrays throughout, except the key column when
+        no key is new."""
+        delta = [np.concatenate(c) for c in zip(*self._pending)]
+        self._pending = []
+        order = np.argsort(delta[0], kind="stable")
+        dk = delta[0][order]
+        last = np.ones(len(dk), bool)
+        last[:-1] = dk[1:] != dk[:-1]
+        delta = [c[order[last]] for c in delta]
+        base = self._snap
+        bk, dk = base[0], delta[0]
+        pos = np.searchsorted(bk, dk)
+        hit = pos < len(bk)
+        hit[hit] = bk[pos[hit]] == dk[hit]
+        new = ~hit
+        if new.any():
+            at_new = pos[new]
+            cols = [np.insert(b, at_new, d[new]) for b, d in zip(base, delta)]
+            # a present key shifts right by the new keys inserted before it
+            at = pos[hit] + np.searchsorted(at_new, pos[hit], side="right")
+        else:
+            cols = [bk] + [b.copy() for b in base[1:]]
+            at = pos[hit]
+        for c, d in zip(cols[1:], delta[1:]):
+            c[at] = d[hit]
+        return tuple(cols)
 
     def get_batch(self, keys: np.ndarray) -> tuple:
         """Vectorized point probe for a key column.
@@ -137,7 +193,10 @@ class Memtable:
             consumed += 1
             if self.bytes >= cap:
                 break
-        self._snap = None
+        # copies: the caller's columns are slices of its request
+        self._pending.append(tuple(
+            np.array(c[:consumed], dt) for c, dt in zip(
+                (keys, seqs, ety, vids, vsizes, vfiles), COLUMN_DTYPES)))
         return consumed
 
     @property

@@ -81,7 +81,8 @@ def lookup_entries(store, keys: np.ndarray, cat: str) -> dict:
             if not unresolved.any():
                 break
             if not mt.snapshot_current:
-                with obs.span(store, "memtable.snapshot", lane):
+                with obs.span(store, "memtable.merge" if mt.snapshot_merges
+                              else "memtable.snapshot", lane):
                     mt.snapshot()
             rows = np.nonzero(unresolved)[0]
             probe = accel.memtable_probe(store, mt, keys[rows])

@@ -40,8 +40,8 @@ _NULL_CTX = contextlib.nullcontext()
 # host clock; ``Observer`` hands back the null context for them, so they
 # never become simulated-lane spans, cause frames or ledger scopes.
 PHASES = frozenset({
-    "read.memtable", "memtable.snapshot", "read.tables", "read.values",
-    "device.upload", "job.pick",
+    "read.memtable", "memtable.snapshot", "memtable.merge",
+    "read.tables", "read.values", "device.upload", "job.pick",
     "gc.read", "gc.lookup", "gc.value_read", "gc.write", "gc.finalize",
     "compact.merge", "compact.cut", "compact.install",
 })

@@ -68,13 +68,20 @@ def _parents(spans, child):
 
 
 @pytest.fixture(scope="module")
-def traced():
+def traced(tmp_path_factory):
     obs = HostClock()
     cfg = EngineConfig.scaled("scavenger", 8 << 20, est_keys=N_KEYS,
                               observer=obs, kernel_min_batch=1)
     store = Store(cfg)
     _drive(store)
     assert store.n_compactions > 0 and store.n_gc_runs > 0
+    # a restored memtable rebuilds its snapshot from the dict on its first
+    # read; every other read merges the writes since the last one
+    keys = np.arange(0, N_KEYS, 7, dtype=np.uint64)
+    store.write(WriteBatch().puts(keys, np.full(len(keys), 600, np.int64)))
+    path = store.checkpoint(tmp_path_factory.mktemp("snap") / "s.ckpt")
+    Store.open(path, observer=obs).multi_get(
+        np.arange(N_KEYS, dtype=np.uint64))
     return obs.spans
 
 
@@ -87,6 +94,7 @@ def test_every_phase_span_appears(traced):
     ("read.tables", {"multi_get", "gc.lookup"}),
     ("read.values", {"multi_get"}),
     ("memtable.snapshot", {"read.memtable"}),
+    ("memtable.merge", {"read.memtable"}),
     ("gc.", {"gc"}),
     ("compact.", {"compact"}),
     ("device.upload", {"read.memtable", "read.tables", "read.values",
