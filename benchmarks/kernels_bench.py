@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core import EngineConfig, Store, WriteBatch, accel
 from repro.core.engine.keys import BloomFilter, hash_family
-from repro.core.values.fetch import split_runs
+from repro.core.values.fetch import plan_runs_host
 from repro.kernels import (bloom_build, bloom_probe, bloom_probe_ref,
                            gc_lookup, gc_lookup_ref, hot_cold_partition,
                            merge_dedup, page_gather, page_gather_ref,
@@ -103,14 +103,13 @@ def _roofline_lookup(rng, rows):
 
 
 def _roofline_coalesce(rng, rows):
-    """Global run planning vs the per-file np.unique + split host planner."""
+    """Run planning on the device vs the NumPy host planner."""
     for m in ROOFLINE_BATCHES:
         rank = np.sort(rng.integers(0, _N_FILES, m))
         pos = rng.integers(0, m // 2, m)
 
         def host():
-            return [split_runs(np.unique(pos[rank == r]), 16)
-                    for r in range(_N_FILES)]
+            return plan_runs_host(rank, pos, 16)
 
         jit_us = _wall(lambda: run_coalesce(rank, pos, window=16))
         host_us = _wall(host)

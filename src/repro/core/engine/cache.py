@@ -6,6 +6,10 @@ inserted at high priority (index/filter blocks, and — Scavenger §III-B.2 —
 DTable's KF index-key blocks) are kept in a protected pool; low-priority data
 blocks evict first.  Capacity is in bytes; hits/misses are counted so
 benchmarks can reproduce the paper's cache-hit-ratio analysis (§II-C).
+Value records are cached per record: ``probe_runs`` probes a whole vSST's
+fetch column in one scalar pass and returns each coalesced run's hits and
+missed bytes, so the value fetch charges its I/O per run without a NumPy
+round per run.
 
 DropCache (Scavenger §III-B.3) is an LRU *key* cache recording keys dropped
 during compaction (over-written / deleted versions = hot-write data).  Flush
@@ -59,24 +63,34 @@ class BlockCache:
             self.low_bytes += nbytes
         self._evict()
 
-    def probe_records(self, fid: int, stream: str, positions, nbytes,
-                      priority: int = PRI_LOW) -> np.ndarray:
-        """Batched lookup-or-insert for per-record cache keys.
+    def probe_runs(self, fid: int, stream: str, positions: list,
+                   nbytes: list, starts: list,
+                   priority: int = PRI_LOW) -> list:
+        """Lookup-or-insert for a file's per-record cache keys, one scalar
+        pass over the whole column, charged per run.
 
-        For each position (in order): a resident record counts a hit and
-        is touched; a missing one is inserted at ``priority`` — exactly the
+        ``positions``/``nbytes``/``starts`` are parallel Python lists;
+        ``starts[i]`` opens a new run (``starts[0]`` is taken as set).  For
+        each position, in order, a resident record counts a hit and is
+        touched; a missing one is inserted at ``priority`` — exactly the
         get-then-put-on-miss sequence of the scalar path, so LRU state and
-        hit/miss counters stay byte-identical.  Returns the hit mask."""
-        hits = np.empty(len(positions), bool)
-        for i, (p, nb) in enumerate(zip(np.asarray(positions).tolist(),
-                                        np.asarray(nbytes).tolist())):
+        hit/miss counters stay byte-identical.  Returns one ``(hits,
+        miss_bytes)`` pair per run, in run order."""
+        runs = []
+        nh = miss = 0
+        for i, (p, nb, s) in enumerate(zip(positions, nbytes, starts)):
+            if s and i:
+                runs.append((nh, miss))
+                nh = miss = 0
             ck = (fid, stream, p)
             if self.get(ck):
-                hits[i] = True
+                nh += 1
             else:
-                hits[i] = False
-                self.put(ck, int(nb), priority)
-        return hits
+                miss += nb
+                self.put(ck, nb, priority)
+        if positions:
+            runs.append((nh, miss))
+        return runs
 
     def erase(self, key) -> None:
         if key in self._high:

@@ -5,15 +5,16 @@ Vectorized planning: one inheritance-chain resolution pass for the whole
 locator column, one ``find`` per touched vSST (not per record), record
 fetches coalesced into adjacent-position runs — one random I/O per run,
 optionally capped at ``EngineConfig.coalesce_window`` records per run.
-Per-record *state* (cache residency, LRU order) is inherently per-entry
-and is handled by the cache layer's batched probe
-(``BlockCache.probe_records``) — that loop is the one per-record step the
-byte-parity contract keeps.
+Runs are planned in one pass over the whole (file-rank, position) column
+in first-occurrence file order: sort, dedup, mark run starts.  Eligible
+batches plan through the ``run_coalesce`` kernel (``core/accel.py``), the
+rest through ``plan_runs_host``, with identical output.
 
-Eligible batches plan through the ``run_coalesce`` kernel
-(``core/accel.py``): one jitted sort/dedup/run-mark pass over the whole
-(file-rank, position) column in first-occurrence file order, replacing the
-per-file ``np.unique`` + ``np.split`` below with identical output.
+Each file's kept positions are then charged in one scalar pass
+(``BlockCache.probe_runs``): per-record cache residency and LRU order are
+inherently per-entry, and that pass is the one per-record step the
+byte-parity contract keeps.  The simulated I/O follows per run, in run
+order: cache hits, then one random read of the missed bytes.
 """
 
 from __future__ import annotations
@@ -25,15 +26,25 @@ from ..engine.cache import BlockCache
 from .resolve import resolve_value_fids
 
 
-def split_runs(posu: np.ndarray, window: int | None) -> list[np.ndarray]:
-    """Adjacent-position runs of a sorted unique position column, each
-    capped at ``window`` records when set (the host-side planner the
-    ``run_coalesce`` kernel mirrors)."""
-    runs = np.split(posu, np.nonzero(np.diff(posu) != 1)[0] + 1)
+def plan_runs_host(rank: np.ndarray, pos: np.ndarray,
+                   window: int | None):
+    """The host planner, one pass over the whole (file-rank, position)
+    column: the ``run_coalesce`` kernel's output, computed with NumPy.
+
+    -> (rank_s, pos_s, keep, run_start), sorted by (rank, pos); duplicates
+    have keep False, and run_start marks the first kept record of each
+    adjacent run, capped at ``window`` kept records when set."""
+    o = np.lexsort((pos, rank))
+    r, p = rank[o], pos[o]
+    keep = np.ones(len(r), bool)
+    keep[1:] = (r[1:] != r[:-1]) | (p[1:] != p[:-1])
+    start = keep.copy()
+    start[1:] &= (r[1:] != r[:-1]) | (p[1:] - p[:-1] > 1)
     if window:
-        runs = [c for r in runs
-                for c in np.split(r, np.arange(window, len(r), window))]
-    return runs
+        kept = np.cumsum(keep)
+        base = np.maximum.accumulate(np.where(start, kept, 0))
+        start |= keep & ((kept - base) % window == 0)
+    return r, p, keep, start
 
 
 def read_values_batch(store, keys, vids, vfiles, vsizes, cat,
@@ -57,55 +68,56 @@ def read_values_batch(store, keys, vids, vfiles, vsizes, cat,
     if not ok.any():
         return
     fsel, ksel, vsel = fids[ok], keys[ok], vids[ok]
-    uniq, first = np.unique(fsel, return_index=True)
-    order = uniq[np.argsort(first)]                 # first-occurrence order
-    window = store.cfg.coalesce_window
+    uniq, first, inv = np.unique(fsel, return_index=True,
+                                 return_inverse=True)
+    by_first = np.argsort(first)
+    order = uniq[by_first].tolist()                 # first-occurrence order
+    rank_of = np.empty(len(uniq), np.int64)
+    rank_of[by_first] = np.arange(len(uniq))
+    row_rank = rank_of[inv]
+    grouped = np.argsort(row_rank, kind="stable")    # rows by file rank
+    ksel, vsel = ksel[grouped], vsel[grouped]
+    lo = np.searchsorted(row_rank[grouped],
+                         np.arange(len(order) + 1)).tolist()
     pos_per_file = []
     # one ``find`` per unique vSST — structure-bounded  # scavlint: allow-loop
-    for fid in order.tolist():
+    for i, fid in enumerate(order):
         t = store.version.value_files[fid]
-        m = fsel == fid
-        pos = accel.table_find(store, t, ksel[m])
+        kf = ksel[lo[i]:lo[i + 1]]
+        pos = accel.table_find(store, t, kf)
         if pos is None:
-            pos = t.find(ksel[m])
+            pos = t.find(kf)
         if strict:
-            assert (pos >= 0).all() and (t.vids[pos] == vsel[m]).all(), \
-                "stale locator"
+            assert (pos >= 0).all() and \
+                (t.vids[pos] == vsel[lo[i]:lo[i + 1]]).all(), "stale locator"
         else:
             pos = pos[pos >= 0]
         pos_per_file.append(pos)
     cat_rank = np.repeat(np.arange(len(order)),
                          [len(p) for p in pos_per_file])
-    cat_pos = (np.concatenate(pos_per_file) if pos_per_file
-               else np.zeros(0, np.int64))
+    cat_pos = np.concatenate(pos_per_file)
     plan = accel.plan_runs(store, cat_rank, cat_pos)
+    if plan is None:
+        plan = plan_runs_host(cat_rank, cat_pos, store.cfg.coalesce_window)
+    r_s, p_s, keep, start = plan
+    r_k, p_k, s_k = r_s[keep], p_s[keep], start[keep]
+    bounds = np.searchsorted(r_k, np.arange(len(order) + 1)).tolist()
     # one vSST per unique fid — structure-bounded  # scavlint: allow-loop
-    for i, fid in enumerate(order.tolist()):        # first-occurrence order
+    for i, fid in enumerate(order):                 # first-occurrence order
         t = store.version.value_files[fid]
-        if plan is None:
-            posu = np.unique(pos_per_file[i])
-            starts = None
-        else:
-            r_s, p_s, keep, start = plan
-            sel = keep & (r_s == i)
-            posu = p_s[sel]
-            starts = start[sel]
+        posu = p_k[bounds[i]:bounds[i + 1]]
         if len(posu) == 0:
             continue
         if t.layout == "rtable":
-            for b in np.unique(t.index_block_of[posu]).tolist():
+            for b in sorted(set(t.index_block_of[posu].tolist())):
                 store.read_block(t, "ib", b, cat, BlockCache.PRI_HIGH,
                                  t.index_block_bytes())
-            runs = (split_runs(posu, window) if starts is None
-                    else np.split(posu, np.nonzero(starts)[0][1:]))
-            for r in runs:
-                rb = t.rec_bytes[r]
-                hits = store.cache.probe_records(t.fid, "rec", r, rb,
-                                                 BlockCache.PRI_LOW)
-                nh = int(hits.sum())
+            runs = store.cache.probe_runs(
+                t.fid, "rec", posu.tolist(), t.rec_bytes[posu].tolist(),
+                s_k[bounds[i]:bounds[i + 1]].tolist(), BlockCache.PRI_LOW)
+            for nh, nbytes in runs:                 # run order
                 if nh:
                     store.io.cache_hit(cat, nh)
-                nbytes = int(rb[~hits].sum())
                 if nbytes:
                     store.io.rand_read(nbytes, cat)
         else:

@@ -331,14 +331,17 @@ def test_interval_rank_property(bounds, queries):
 
 # -------------------------------------------- run_coalesce (fetch planning)
 def _coalesce_oracle(rank, pos, window):
-    """Per-rank np.unique + adjacency split + window chunking — the host
-    planner in core/values/fetch.py."""
-    from repro.core.values.fetch import split_runs
+    """Per-rank np.unique + adjacency split + window chunking: the run
+    plan ``run_coalesce`` and the host planner must both give."""
     out = []
     for r in np.unique(rank):
         posu = np.unique(pos[rank == r])
-        out.append((int(r), [c.tolist()
-                             for c in split_runs(posu, window)]))
+        runs = np.split(posu, np.nonzero(np.diff(posu) != 1)[0] + 1)
+        if window:
+            runs = [c for run in runs
+                    for c in np.split(run, np.arange(window, len(run),
+                                                     window))]
+        out.append((int(r), [c.tolist() for c in runs]))
     return out
 
 
@@ -351,23 +354,38 @@ def _runs_from_kernel(rank_s, pos_s, keep, start):
     return out
 
 
+def _coalesce_case(case, window, m):
+    rng = np.random.default_rng(hash((case, window, m)) % (1 << 32))
+    if case == "empty":
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
+    if case == "single":
+        return np.array([3]), np.array([77])
+    if case == "dups":                          # all-duplicate positions
+        return np.zeros(m, np.int64), np.full(m, 5, np.int64)
+    return rng.integers(0, 5, m), rng.integers(0, m // 2, m)  # odd length
+
+
 @pytest.mark.parametrize("m", [100, 4097])
 @pytest.mark.parametrize("window", [None, 1, 3, 16])
 @pytest.mark.parametrize("case", ["empty", "single", "dups", "mixed"])
 def test_run_coalesce_matches_host_planner(case, window, m):
-    rng = np.random.default_rng(hash((case, window, m)) % (1 << 32))
-    if case == "empty":
-        rank = pos = np.zeros(0, np.int64)
-    elif case == "single":
-        rank, pos = np.array([3]), np.array([77])
-    elif case == "dups":
-        rank = np.zeros(m, np.int64)
-        pos = np.full(m, 5, np.int64)           # all-duplicate positions
-    else:
-        rank = rng.integers(0, 5, m)            # non-power-of-two length
-        pos = rng.integers(0, m // 2, m)
+    rank, pos = _coalesce_case(case, window, m)
     got = run_coalesce(rank, pos, window=window)
     assert _runs_from_kernel(*got) == _coalesce_oracle(rank, pos, window)
+
+
+@pytest.mark.parametrize("m", [100, 4097])
+@pytest.mark.parametrize("window", [None, 1, 3, 16])
+@pytest.mark.parametrize("case", ["empty", "single", "dups", "mixed"])
+def test_host_planner_matches_run_coalesce(case, window, m):
+    """``plan_runs_host`` (core/values/fetch.py) returns the kernel's
+    arrays: same order, same keep and run-start masks."""
+    from repro.core.values.fetch import plan_runs_host
+    rank, pos = _coalesce_case(case, window, m)
+    host = plan_runs_host(rank, pos, window)
+    for h, k in zip(host, run_coalesce(rank, pos, window=window)):
+        assert_array_equal(h, k)
+    assert _runs_from_kernel(*host) == _coalesce_oracle(rank, pos, window)
 
 
 @settings(max_examples=25, deadline=None)
